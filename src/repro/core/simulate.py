@@ -606,58 +606,68 @@ def _scan_sim(prog: Program, p_blocks, seed, st: SimStatic, ax: SimAxes,
                                  ax.table_ema)
 
     def body(carry: Carry, ep_i):
+        # named scopes mark the epoch's phases in the executable's op_name
+        # metadata (a profiler's op view groups device time by them)
         pos = carry.pos
-        ctx = _epoch_context(prog, pos, p_blocks, seed)
+        with jax.named_scope("engine.context"):
+            ctx = _epoch_context(prog, pos, p_blocks, seed)
 
         hit_rate = None
         c_f = I_f = I_pred_f = idx_lu = None
-        if is_static_f:
-            fidx = jnp.full((st.n_cu,), spec.static_fidx, jnp.int32)
-            f_sel = F[fidx]
-            committed, ctr = _execute_ctx(ctx, pos, f_sel, p_blocks, ax)
-        else:
-            # --- predict I(f) from carry state (no forks needed) ----------
-            idx_lu = PRED.table_index(ctx.blk, st.entries, st.offset_blocks)
-            # custom pc-family specs keep the standard table machinery
-            # (lookup telemetry here, counter-driven update below): their
-            # predict hook reads carry.table and customizes only prediction
-            if (not static_mech) or is_pc or (is_custom
-                                              and spec.family == "pc"):
-                I_pc, hit = _pc_lookup(carry, idx_lu)
-                hit_rate = hit.mean()
-            if (not static_mech) or is_react:
-                I_react = _predict_instr(carry.react_i0, carry.react_sens,
-                                         st, ax)
-            if static_mech and is_custom:
-                # registered mechanism: the spec's predictor hook supplies
-                # I(f) from the same carry/context view the builtins see
-                I_hook = spec.predict(carry, ctx, st, ax)
-            pbar = (carry.e_acc / jnp.maximum(carry.t_acc, 1e-3)) \
-                .reshape(n_dom, st.cus_per_domain).sum(1)
-
-            if static_mech and is_oracle:
-                # oracle's prediction IS this epoch's forks -> forks first,
-                # then the mixed-frequency row (still sharing the context).
-                c_f = _steady_parts(ctx, pos, F_rows, p_blocks, ax).steady
-                I_f = c_f.sum(-1).T
-                I_pred_f = I_f
-                fidx = _select_freq(I_pred_f, st, ax, pbar)
-                f_sel = F[fidx]
-                committed, ctr = _execute_ctx(ctx, pos, f_sel, p_blocks, ax)
+        oracle_now = static_mech and is_oracle
+        with jax.named_scope("engine.predict"):
+            if is_static_f:
+                fidx = jnp.full((st.n_cu,), spec.static_fidx, jnp.int32)
             else:
-                # fused fork--pre-execute: for every non-oracle mechanism the
-                # selection depends only on carry, so the 10 uniform fork
-                # rows and the chosen mixed row run as one 11-way batched
-                # execute over the shared context; barrier/contention
-                # counters materialize only for row 10. (The traced family
-                # therefore excludes oracle — run_suite routes it to its own
-                # specialized executable.)
-                if static_mech:
+                # --- predict I(f) from carry state (no forks needed) ------
+                idx_lu = PRED.table_index(ctx.blk, st.entries,
+                                          st.offset_blocks)
+                # custom pc-family specs keep the standard table machinery
+                # (lookup telemetry here, counter-driven update below):
+                # their predict hook reads carry.table and customizes only
+                # prediction
+                if (not static_mech) or is_pc or (is_custom
+                                                  and spec.family == "pc"):
+                    I_pc, hit = _pc_lookup(carry, idx_lu)
+                    hit_rate = hit.mean()
+                if (not static_mech) or is_react:
+                    I_react = _predict_instr(carry.react_i0,
+                                             carry.react_sens, st, ax)
+                if static_mech and is_custom:
+                    # registered mechanism: the spec's predictor hook
+                    # supplies I(f) from the same carry/context view the
+                    # builtins see
+                    I_hook = spec.predict(carry, ctx, st, ax)
+                pbar = (carry.e_acc / jnp.maximum(carry.t_acc, 1e-3)) \
+                    .reshape(n_dom, st.cus_per_domain).sum(1)
+
+                if oracle_now:
+                    # oracle's prediction IS this epoch's forks -> forks
+                    # first, then the mixed-frequency row (still sharing
+                    # the context).
+                    c_f = _steady_parts(ctx, pos, F_rows, p_blocks,
+                                        ax).steady
+                    I_f = c_f.sum(-1).T
+                    I_pred_f = I_f
+                elif static_mech:
                     I_pred_f = I_hook if is_custom else \
                         (I_pc if is_pc else I_react)
                 else:
                     I_pred_f = jnp.where(mech < _N_REACT, I_react, I_pc)
                 fidx = _select_freq(I_pred_f, st, ax, pbar)
+
+        with jax.named_scope("engine.execute"):
+            if is_static_f or oracle_now:
+                f_sel = F[fidx]
+                committed, ctr = _execute_ctx(ctx, pos, f_sel, p_blocks, ax)
+            else:
+                # fused fork--pre-execute: for every non-oracle mechanism
+                # the selection depends only on carry, so the 10 uniform
+                # fork rows and the chosen mixed row run as one 11-way
+                # batched execute over the shared context;
+                # barrier/contention counters materialize only for row 10.
+                # (The traced family therefore excludes oracle — run_suite
+                # routes it to its own specialized executable.)
                 f_all = jnp.concatenate([F_rows, F[fidx][None]], axis=0)
                 parts = _steady_parts(ctx, pos, f_all, p_blocks, ax)
                 c_f = parts.steady[:NF]                     # (NF,CU,WF)
@@ -667,109 +677,122 @@ def _scan_sim(prog: Program, p_blocks, seed, st: SimStatic, ax: SimAxes,
                 f_sel = f_all[NF]
                 I_f = c_f.sum(-1).T                         # (CU,NF)
 
-        # --- transition overhead + counter views --------------------------
-        trans = (f_sel != carry.f_prev)
-        committed = committed * (1.0 - lat_us / T * trans[:, None])
-        I_actual = ctr["steady"].sum(-1)                 # (CU,) counter view
-        work_actual = committed.sum(-1)                  # (CU,) real progress
-        # --- accuracy of the prediction for THIS epoch --------------------
-        if I_pred_f is not None:
-            I_at_sel = jnp.take_along_axis(I_pred_f, fidx[:, None], 1)[:, 0]
-            err = jnp.abs(I_at_sel - I_actual) / jnp.maximum(I_actual, 1e-3)
-        else:
-            err = jnp.zeros((st.n_cu,))
-        # --- energy --------------------------------------------------------
-        act = work_actual / (ax.cap_per_ghz * f_sel * T * st.n_wf)
-        energy = PWR.power(f_sel, act, ax.power) * T \
-            + PWR.transition_energy(carry.f_prev, f_sel, ax.power) * trans
-        # --- estimation + state update -------------------------------------
-        new = carry._replace(pos=pos + committed, f_prev=f_sel,
-                             e_acc=carry.e_acc + energy,
-                             t_acc=carry.t_acc + T)
-        est_ctrs = dict(ctr, committed=ctr["steady"])
-        if static_mech:
-            if is_custom:
-                if spec.family == "pc":
-                    # standard counter-driven table maintenance (pcstall's
-                    # estimator path) so a registered pc-family predictor
-                    # sees a live table without reimplementing it
-                    i0_wf, s_wf = EST.wf_stall_estimate(est_ctrs, f_sel)
+            # --- transition overhead + counter views ----------------------
+            trans = (f_sel != carry.f_prev)
+            committed = committed * (1.0 - lat_us / T * trans[:, None])
+            I_actual = ctr["steady"].sum(-1)             # (CU,) counter view
+            work_actual = committed.sum(-1)              # (CU,) real progress
+            # --- accuracy of the prediction for THIS epoch ----------------
+            if I_pred_f is not None:
+                I_at_sel = jnp.take_along_axis(I_pred_f, fidx[:, None],
+                                               1)[:, 0]
+                err = jnp.abs(I_at_sel - I_actual) \
+                    / jnp.maximum(I_actual, 1e-3)
+            else:
+                err = jnp.zeros((st.n_cu,))
+            # --- energy ----------------------------------------------------
+            act = work_actual / (ax.cap_per_ghz * f_sel * T * st.n_wf)
+            energy = PWR.power(f_sel, act, ax.power) * T \
+                + PWR.transition_energy(carry.f_prev, f_sel, ax.power) \
+                * trans
+
+        with jax.named_scope("engine.update"):
+            # --- estimation + state update ---------------------------------
+            new = carry._replace(pos=pos + committed, f_prev=f_sel,
+                                 e_acc=carry.e_acc + energy,
+                                 t_acc=carry.t_acc + T)
+            est_ctrs = dict(ctr, committed=ctr["steady"])
+            if static_mech:
+                if is_custom:
+                    if spec.family == "pc":
+                        # standard counter-driven table maintenance
+                        # (pcstall's estimator path) so a registered
+                        # pc-family predictor sees a live table without
+                        # reimplementing it
+                        i0_wf, s_wf = EST.wf_stall_estimate(est_ctrs, f_sel)
+                        i0_wf, s_wf = i0_wf / T, s_wf / T
+                        tbl = _table_update(carry, idx_lu, i0_wf, s_wf)
+                        new = new._replace(table=tbl, wf_i0=i0_wf,
+                                           wf_sens=s_wf)
+                    if spec.update is not None:
+                        upd = spec.update(est_ctrs, f_sel, I_f, carry, ctx,
+                                          st, ax)
+                        if upd is not None:
+                            new = new._replace(react_i0=upd[0],
+                                               react_sens=upd[1])
+                elif is_react and not spec.fork_estimator:
+                    i0_cu, s_cu = EST.cu_estimate(est_ctrs, f_sel,
+                                                  spec.cu_model)
+                    new = new._replace(react_i0=i0_cu / T,
+                                       react_sens=s_cu / T)
+                elif is_react:  # fork-accurate reactive: exact from forks
+                    sens_cu = (I_f[:, -1] - I_f[:, 0]) \
+                        / ((F[-1] - F[0]) * T)
+                    i0_cu = I_f[:, 0] / T - sens_cu * F[0]
+                    new = new._replace(react_i0=i0_cu, react_sens=sens_cu)
+                elif is_pc:
+                    if not spec.fork_estimator:  # counter-driven (pcstall)
+                        i0_wf, s_wf = EST.wf_stall_estimate(est_ctrs, f_sel)
+                    else:  # exact per-WF linear model from the forks (accpc)
+                        i0_wf, s_wf = _true_wf_linear(c_f, F)
                     i0_wf, s_wf = i0_wf / T, s_wf / T
                     tbl = _table_update(carry, idx_lu, i0_wf, s_wf)
-                    new = new._replace(table=tbl, wf_i0=i0_wf,
-                                       wf_sens=s_wf)
-                if spec.update is not None:
-                    upd = spec.update(est_ctrs, f_sel, I_f, carry, ctx,
-                                      st, ax)
-                    if upd is not None:
-                        new = new._replace(react_i0=upd[0],
-                                           react_sens=upd[1])
-            elif is_react and not spec.fork_estimator:
-                i0_cu, s_cu = EST.cu_estimate(est_ctrs, f_sel, spec.cu_model)
-                new = new._replace(react_i0=i0_cu / T, react_sens=s_cu / T)
-            elif is_react:  # fork-accurate reactive: exact linear from forks
-                sens_cu = (I_f[:, -1] - I_f[:, 0]) / ((F[-1] - F[0]) * T)
-                i0_cu = I_f[:, 0] / T - sens_cu * F[0]
-                new = new._replace(react_i0=i0_cu, react_sens=sens_cu)
-            elif is_pc:
-                if not spec.fork_estimator:  # counter-driven (pcstall)
-                    i0_wf, s_wf = EST.wf_stall_estimate(est_ctrs, f_sel)
-                else:  # exact per-WF linear model from the forks (accpc)
-                    i0_wf, s_wf = _true_wf_linear(c_f, F)
-                i0_wf, s_wf = i0_wf / T, s_wf / T
-                tbl = _table_update(carry, idx_lu, i0_wf, s_wf)
-                new = new._replace(table=tbl, wf_i0=i0_wf, wf_sens=s_wf)
-        else:
-            # traced mechanism id: evaluate every estimator (cheap next to
-            # the batched executes) and select, so one executable serves the
-            # whole fork-mechanism family under vmap. Case order follows
-            # the registry's traced ids (asserted at import: counter models
-            # 0..n-2, the fork-accurate reactive last).
-            cu_ests = [EST.cu_estimate(est_ctrs, f_sel, s.cu_model)
-                       for s in _REACT_SPECS if not s.fork_estimator]
-            sens_ar = (I_f[:, -1] - I_f[:, 0]) / ((F[-1] - F[0]) * T)
-            i0_ar = I_f[:, 0] / T - sens_ar * F[0]
-            sel = [mech == k for k in range(_N_REACT)]
-            r_i0 = jnp.select(sel, [e[0] / T for e in cu_ests] + [i0_ar],
-                              carry.react_i0)
-            r_se = jnp.select(sel, [e[1] / T for e in cu_ests] + [sens_ar],
-                              carry.react_sens)
-            new = new._replace(react_i0=r_i0, react_sens=r_se)
-            i0_est, s_est = EST.wf_stall_estimate(est_ctrs, f_sel)
-            i0_tr, s_tr = _true_wf_linear(c_f, F)
-            i0_wf = jnp.where(mech == _ID_CTR_PC, i0_est, i0_tr) / T
-            s_wf = jnp.where(mech == _ID_CTR_PC, s_est, s_tr) / T
-            tbl_u = _table_update(carry, idx_lu, i0_wf, s_wf)
-            pc_now = functools.reduce(
-                lambda a, b: a | b, [mech == i for i in _PC_IDS])
-            tbl = jax.tree.map(lambda a, b: jnp.where(pc_now, a, b),
-                               tbl_u, carry.table)
-            new = new._replace(
-                table=tbl,
-                wf_i0=jnp.where(pc_now, i0_wf, carry.wf_i0),
-                wf_sens=jnp.where(pc_now, s_wf, carry.wf_sens))
-        # true CU sensitivity for phase-variability analyses
-        if is_static_f:
-            true_sens_cu = jnp.zeros((st.n_cu,))
-        else:
-            true_sens_cu = (I_f[:, -1] - I_f[:, 0]) / ((F[-1] - F[0]) * T)
-        ys = {"work": work_actual, "energy": energy, "err": err,
-              "fidx": fidx.astype(jnp.int8), "true_sens": true_sens_cu}
-        # emit the channel only when the spec declares it (custom pc specs
-        # may decline), so run_sim and the sweep layer agree on the trace
-        # schema; the traced family (spec is None) emits for all and the
-        # sweep layer filters per spec on unpack
-        if hit_rate is not None and (spec is None or spec.hit_telemetry):
-            ys["hit_rate"] = hit_rate
-        if st.record_wf and not is_static_f:
-            ys["wf_sens"] = ((c_f[-1] - c_f[0]) / (F[-1] - F[0])) \
-                .astype(jnp.float32)
-            ys["wf_blk"] = ctx.blk.astype(jnp.int32)
-        # logical-epoch mask: grid points shorter than the static scan
-        # length report zeros past their tail (state keeps advancing, but
-        # the scan is causal so live epochs are unaffected)
-        live = ep_i < ax.n_ep
-        ys = jax.tree.map(lambda v: jnp.where(live, v, jnp.zeros_like(v)), ys)
+                    new = new._replace(table=tbl, wf_i0=i0_wf, wf_sens=s_wf)
+            else:
+                # traced mechanism id: evaluate every estimator (cheap next
+                # to the batched executes) and select, so one executable
+                # serves the whole fork-mechanism family under vmap. Case
+                # order follows the registry's traced ids (asserted at
+                # import: counter models 0..n-2, the fork-accurate reactive
+                # last).
+                cu_ests = [EST.cu_estimate(est_ctrs, f_sel, s.cu_model)
+                           for s in _REACT_SPECS if not s.fork_estimator]
+                sens_ar = (I_f[:, -1] - I_f[:, 0]) / ((F[-1] - F[0]) * T)
+                i0_ar = I_f[:, 0] / T - sens_ar * F[0]
+                sel = [mech == k for k in range(_N_REACT)]
+                r_i0 = jnp.select(sel, [e[0] / T for e in cu_ests] + [i0_ar],
+                                  carry.react_i0)
+                r_se = jnp.select(sel,
+                                  [e[1] / T for e in cu_ests] + [sens_ar],
+                                  carry.react_sens)
+                new = new._replace(react_i0=r_i0, react_sens=r_se)
+                i0_est, s_est = EST.wf_stall_estimate(est_ctrs, f_sel)
+                i0_tr, s_tr = _true_wf_linear(c_f, F)
+                i0_wf = jnp.where(mech == _ID_CTR_PC, i0_est, i0_tr) / T
+                s_wf = jnp.where(mech == _ID_CTR_PC, s_est, s_tr) / T
+                tbl_u = _table_update(carry, idx_lu, i0_wf, s_wf)
+                pc_now = functools.reduce(
+                    lambda a, b: a | b, [mech == i for i in _PC_IDS])
+                tbl = jax.tree.map(lambda a, b: jnp.where(pc_now, a, b),
+                                   tbl_u, carry.table)
+                new = new._replace(
+                    table=tbl,
+                    wf_i0=jnp.where(pc_now, i0_wf, carry.wf_i0),
+                    wf_sens=jnp.where(pc_now, s_wf, carry.wf_sens))
+            # true CU sensitivity for phase-variability analyses
+            if is_static_f:
+                true_sens_cu = jnp.zeros((st.n_cu,))
+            else:
+                true_sens_cu = (I_f[:, -1] - I_f[:, 0]) \
+                    / ((F[-1] - F[0]) * T)
+            ys = {"work": work_actual, "energy": energy, "err": err,
+                  "fidx": fidx.astype(jnp.int8), "true_sens": true_sens_cu}
+            # emit the channel only when the spec declares it (custom pc
+            # specs may decline), so run_sim and the sweep layer agree on
+            # the trace schema; the traced family (spec is None) emits for
+            # all and the sweep layer filters per spec on unpack
+            if hit_rate is not None and (spec is None or spec.hit_telemetry):
+                ys["hit_rate"] = hit_rate
+            if st.record_wf and not is_static_f:
+                ys["wf_sens"] = ((c_f[-1] - c_f[0]) / (F[-1] - F[0])) \
+                    .astype(jnp.float32)
+                ys["wf_blk"] = ctx.blk.astype(jnp.int32)
+            # logical-epoch mask: grid points shorter than the static scan
+            # length report zeros past their tail (state keeps advancing,
+            # but the scan is causal so live epochs are unaffected)
+            live = ep_i < ax.n_ep
+            ys = jax.tree.map(
+                lambda v: jnp.where(live, v, jnp.zeros_like(v)), ys)
         return new, ys
 
     def body_v2(carry: Carry, ep_i):
@@ -779,19 +802,21 @@ def _scan_sim(prog: Program, p_blocks, seed, st: SimStatic, ax: SimAxes,
         # noise is computed here: the kernel's module docstring explains
         # why eps must not be recomputed in a different fusion context
         # (the unused context gathers are DCE'd).
-        eps = _epoch_context(prog, carry.pos, p_blocks, seed).eps
-        out = KEF.epoch_fused(
-            prog.i0_rate, prog.sens_rate, cum_t, carry.pos, F, eps,
-            carry.f_prev, carry.e_acc, carry.t_acc,
-            p_blocks=p_blocks, epoch_us=T, sigma=ax.sigma,
-            cap_per_ghz=ax.cap_per_ghz, membw=ax.membw, obj=ax.obj,
-            lat_us=lat_us, power=ax.power,
-            cus_per_domain=st.cus_per_domain,
-            table=carry.table, tid=tid, wf_i0=carry.wf_i0,
-            wf_sens=carry.wf_sens, table_ema=ax.table_ema,
-            offset_blocks=st.offset_blocks,
-            react_i0=carry.react_i0, react_sens=carry.react_sens,
-            **v2_kw)
+        with jax.named_scope("engine.context"):
+            eps = _epoch_context(prog, carry.pos, p_blocks, seed).eps
+        with jax.named_scope("engine.kernel"):
+            out = KEF.epoch_fused(
+                prog.i0_rate, prog.sens_rate, cum_t, carry.pos, F, eps,
+                carry.f_prev, carry.e_acc, carry.t_acc,
+                p_blocks=p_blocks, epoch_us=T, sigma=ax.sigma,
+                cap_per_ghz=ax.cap_per_ghz, membw=ax.membw, obj=ax.obj,
+                lat_us=lat_us, power=ax.power,
+                cus_per_domain=st.cus_per_domain,
+                table=carry.table, tid=tid, wf_i0=carry.wf_i0,
+                wf_sens=carry.wf_sens, table_ema=ax.table_ema,
+                offset_blocks=st.offset_blocks,
+                react_i0=carry.react_i0, react_sens=carry.react_sens,
+                **v2_kw)
         new = carry._replace(pos=out.pos, f_prev=out.f_sel,
                              e_acc=out.e_acc, t_acc=out.t_acc[0])
         if spec is None:

@@ -87,6 +87,15 @@ reference op order for scan-equivalence tests). ``SimConfig.pallas_block_cu``
 additionally selects the blocked ``(CU,)``-grid kernel pair for large CU
 counts (fork family only, lean math; ignored on the direct-eval interpret
 engine).
+
+Host spans: the layer writes ``jax.profiler.TraceAnnotation`` spans named
+``repro.sweep.*`` on the profiler's clock, beside the device operations
+of a trace: ``prep`` (grid points through the flattened operands),
+``dispatch`` (one executable family's carry build and enqueue), ``wait``
+(the fork family cut per mechanism, then every family's outputs ready),
+``unpack`` (the result dict)
+and ``copy`` (one channel array sliced and copied to the host). With no
+profiler running each costs a few microseconds.
 """
 from __future__ import annotations
 
@@ -129,8 +138,10 @@ def _unpack_trace(arrs: Dict[str, jnp.ndarray], i: int, spec: MechanismSpec,
     family computes it for all; registered PC-family mechanisms get the
     channel by setting ``hit_telemetry`` — no sweep-layer edit needed)."""
     ep = slice(None) if n_ep is None else slice(None, n_ep)
-    tr = {k: np.asarray(v[i, 0, ep] if squeeze_seed else v[i, :, ep])
-          for k, v in arrs.items()}
+    tr = {}
+    for k, v in arrs.items():
+        with jax.profiler.TraceAnnotation("repro.sweep.copy"):
+            tr[k] = np.asarray(v[i, 0, ep] if squeeze_seed else v[i, :, ep])
     if not spec.hit_telemetry:
         tr.pop("hit_rate", None)
     return tr
@@ -345,10 +356,11 @@ def _run_family(st: SimStatic, n_dev: int,
     family = "grid_forks" if mechanism is None else f"grid_{mechanism.name}"
     with _COUNTER_LOCK:
         DISPATCH_ROWS[family] += n_flat * max(int(mech_ids.shape[0]), 1)
-    # the initial scan carry is rebuilt per dispatch: it is donated to the
-    # executable, which invalidates its buffers
-    carry0 = _carry_builder(st)(p_log_flat)
-    with warnings.catch_warnings():
+    with jax.profiler.TraceAnnotation("repro.sweep.dispatch"), \
+            warnings.catch_warnings():
+        # the initial scan carry is rebuilt per dispatch: it is donated to
+        # the executable, which invalidates its buffers
+        carry0 = _carry_builder(st)(p_log_flat)
         # The donated carry can never alias into the executable's outputs
         # (the traces carry epoch/seed/mech axes the carry lacks), so XLA's
         # "not usable" warning is expected by construction on every
@@ -455,25 +467,6 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
     squeezed unless ``seeds`` is given, epoch axis cut to the point's
     logical ``n_epochs``).
     """
-    if isinstance(programs, dict):
-        names_w = list(programs)
-        progs = [programs[n] for n in names_w]
-    else:
-        progs = list(programs)
-        names_w = [p.name for p in progs]
-    assert progs, "run_grid needs at least one program"
-    specs = [MECH.resolve(m) for m in mechanisms]
-    if dedup:
-        # Refuse under-declared specs BEFORE any dispatch: dedup
-        # broadcasts one scan across every grid point agreeing on a
-        # spec's declared live axes, so a trace reading an undeclared
-        # axis would get silently wrong results. The audit (a tiny
-        # make_jaxpr, no compile) is cached per spec per process —
-        # builtins and repeat grids pay nothing after the first call.
-        from repro.analysis.deps import require_dedup_sound
-        for s in specs:
-            require_dedup_sound(s)
-    assert static_cfg.n_cu % static_cfg.cus_per_domain == 0
     axis_names, points = _grid_points(axes_grid)
     keys = [tuple(p[n] for n in axis_names) for p in points]
     assert len(set(keys)) == len(keys), "duplicate grid points"
@@ -498,24 +491,47 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
             # restore the caller's grid-point order
             return {k: out[k] for k in keys}
 
-    squeeze_seed = seeds is None
-    seed_arr = jnp.asarray(SIM.seed_i32(
-        [static_cfg.seed] if seeds is None else list(seeds)))
-    stacked, p_logical = _stack_programs(progs)
-    W, G = len(progs), len(points)
+    with jax.profiler.TraceAnnotation("repro.sweep.prep"):
+        if isinstance(programs, dict):
+            names_w = list(programs)
+            progs = [programs[n] for n in names_w]
+        else:
+            progs = list(programs)
+            names_w = [p.name for p in progs]
+        assert progs, "run_grid needs at least one program"
+        specs = [MECH.resolve(m) for m in mechanisms]
+        if dedup:
+            # Refuse under-declared specs BEFORE any dispatch: dedup
+            # broadcasts one scan across every grid point agreeing on a
+            # spec's declared live axes, so a trace reading an undeclared
+            # axis would get silently wrong results. The audit (a tiny
+            # make_jaxpr, no compile) is cached per spec per process —
+            # builtins and repeat grids pay nothing after the first call.
+            from repro.analysis.deps import require_dedup_sound
+            for s in specs:
+                require_dedup_sound(s)
+        assert static_cfg.n_cu % static_cfg.cus_per_domain == 0
 
-    sims = [dataclasses.replace(static_cfg, **p) for p in points]
-    n_ep_max = max(s.n_epochs for s in sims)
-    # the ladder length is the one *static* field a power regime carries:
-    # it sets shapes, so a grid may sweep regimes but not n_freqs
-    pstats = {s.power.static_part() for s in sims}
-    assert len(pstats) == 1, \
-        f"power grid values must share one ladder length, got {pstats}"
-    st = sims[0].static_part(n_epochs=n_ep_max)
-    # never shard wider than the flat axis: a 1-point manager report on an
-    # 8-device host would otherwise pad one row to 8 identical scans
-    n_dev = min(jax.local_device_count(), W * G)
-    full_ops = _flat_operands(stacked, p_logical, sims, n_dev)
+        squeeze_seed = seeds is None
+        seed_arr = jnp.asarray(SIM.seed_i32(
+            [static_cfg.seed] if seeds is None else list(seeds)))
+        stacked, p_logical = _stack_programs(progs)
+        W, G = len(progs), len(points)
+
+        sims = [dataclasses.replace(static_cfg, **p) for p in points]
+        n_ep_max = max(s.n_epochs for s in sims)
+        # the ladder length is the one *static* field a power regime
+        # carries: it sets shapes, so a grid may sweep regimes but not
+        # n_freqs
+        pstats = {s.power.static_part() for s in sims}
+        assert len(pstats) == 1, \
+            f"power grid values must share one ladder length, got {pstats}"
+        st = sims[0].static_part(n_epochs=n_ep_max)
+        # never shard wider than the flat axis: a 1-point manager report
+        # on an 8-device host would otherwise pad one row to 8 identical
+        # scans
+        n_dev = min(jax.local_device_count(), W * G)
+        full_ops = _flat_operands(stacked, p_logical, sims, n_dev)
 
     def classes_of(spec: MechanismSpec):
         """Grid-point equivalence classes of one spec's live axes."""
@@ -536,12 +552,17 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
         key = tuple(class_of)
         if key not in ops_cache:
             dev = min(jax.local_device_count(), W * len(class_sims))
-            ops_cache[key] = (_flat_operands(stacked, p_logical, class_sims,
-                                             dev), dev)
+            with jax.profiler.TraceAnnotation("repro.sweep.prep"):
+                ops_cache[key] = (_flat_operands(stacked, p_logical,
+                                                 class_sims, dev), dev)
         return ops_cache[key]
 
     # per-mechanism result row-lookup: name -> (arrays, class_of, n_classes)
     by_mech: Dict[str, Tuple[Dict[str, jnp.ndarray], List[int], int]] = {}
+    # fork families' outputs, cut per mechanism once every family is
+    # enqueued: (outputs, mechanisms, class_of, n_classes)
+    fork_out: List[Tuple[Dict[str, jnp.ndarray], List[MechanismSpec],
+                         List[int], int]] = []
     no_ids = jnp.zeros((0,), jnp.int32)  # specialized mechs ignore mech_ids
 
     # Traced fork-family mechanisms share executables; group them by the
@@ -565,10 +586,8 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
         ops, dev = class_operands(class_of, class_sims)
         ids = jnp.asarray([SIM.FORK_MECH_IDS[s.name] for s in group],
                           jnp.int32)
-        ys = _run_family(st, dev, None, ops, seed_arr, ids)
-        for j, s in enumerate(group):
-            by_mech[s.name] = ({k: v[:, :, j] for k, v in ys.items()},
-                               class_of, len(class_sims))
+        fork_out.append((_run_family(st, dev, None, ops, seed_arr, ids),
+                         group, class_of, len(class_sims)))
 
     # Specialized families — static frequencies, oracle, and registered
     # custom mechanisms — compile their own executable and dedup the same
@@ -582,17 +601,29 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
         ys = _run_family(st, dev, s, ops, seed_arr, no_ids)
         by_mech[s.name] = (ys, class_of, len(class_sims))
 
+    with jax.profiler.TraceAnnotation("repro.sweep.wait"):
+        # The per-mechanism cuts are small device programs queued behind
+        # every family. A device holds a bounded number of programs in
+        # flight, so enqueued before the specialized families they held
+        # the host until the fork family ended; here the host waits on
+        # them as on the results.
+        for ys, group, class_of, C in fork_out:
+            for j, s in enumerate(group):
+                by_mech[s.name] = ({k: v[:, :, j] for k, v in ys.items()},
+                                   class_of, C)
+        jax.block_until_ready([arrs for arrs, _, _ in by_mech.values()])
     out: Dict[tuple, Dict[str, Dict[str, Dict[str, np.ndarray]]]] = {}
-    for g, (key, sim_pt) in enumerate(zip(keys, sims)):
-        out[key] = {}
-        for w, name in enumerate(names_w):
-            trs = {}
-            for s in specs:
-                arrs, class_of, C = by_mech[s.name]
-                trs[s.name] = _unpack_trace(arrs, w * C + class_of[g], s,
-                                            squeeze_seed,
-                                            n_ep=sim_pt.n_epochs)
-            out[key][name] = trs
+    with jax.profiler.TraceAnnotation("repro.sweep.unpack"):
+        for g, (key, sim_pt) in enumerate(zip(keys, sims)):
+            out[key] = {}
+            for w, name in enumerate(names_w):
+                trs = {}
+                for s in specs:
+                    arrs, class_of, C = by_mech[s.name]
+                    trs[s.name] = _unpack_trace(arrs, w * C + class_of[g], s,
+                                                squeeze_seed,
+                                                n_ep=sim_pt.n_epochs)
+                out[key][name] = trs
     return out
 
 

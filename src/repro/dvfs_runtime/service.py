@@ -30,9 +30,21 @@ turns the sweep substrate into that service:
   reports (``repro.dvfs_runtime.manager.point_report``) and resolves the
   futures.
 
-``stats()`` reports sustained jobs/sec and dispatch-latency percentiles —
-the ``serve_stream`` benchmark record is built from exactly these
-counters.
+``stats()`` reports sustained jobs/sec and job-latency percentiles, each
+job timed from ``submit`` until its report is built and its future
+resolved — the ``serve_stream`` benchmark record is built from exactly
+these counters.
+
+Host spans (``jax.profiler.TraceAnnotation``, on the profiler's clock, a
+few microseconds each with no profiler running), each tagged with the
+batch number ``batch`` and its real job count ``jobs``: the dispatcher
+writes ``repro.service.coalesce`` (first request taken until the batch
+closes; ``batch`` only), ``repro.service.admit`` (waiting for an
+in-flight slot) and ``repro.service.dispatch`` (staging and enqueue,
+holding the sweep layer's ``repro.sweep.dispatch``); the collector writes
+``repro.service.wait`` (device results ready), ``repro.service.unpack``
+(per-job traces to the host, holding ``repro.sweep.copy``) and
+``repro.service.report`` (reports built, futures resolved).
 """
 from __future__ import annotations
 
@@ -43,6 +55,7 @@ import time
 from concurrent.futures import Future
 from typing import Dict, Iterable, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.configs.base import ModelConfig, ShapeConfig
@@ -72,7 +85,7 @@ class DVFSService:
     """A long-lived streaming front-end over one :class:`GridExecutor`.
 
     ``submit(program, axes, telemetry)`` returns a Future immediately; its
-    result is ``{"traces", "report", "latency_s", "batch_size"}`` where
+    result is ``{"traces", "report", "batch_size"}`` where
     ``traces`` is the job's ``{mechanism: trace}`` dict (bitwise-equal to
     a one-shot ``run_grid`` over the same job) and ``report`` is the
     manager-schema point report against the service baseline, including
@@ -113,7 +126,9 @@ class DVFSService:
         self._q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._done: "queue.SimpleQueue" = queue.SimpleQueue()
         self._inflight = threading.BoundedSemaphore(depth)
-        self._lock = threading.Lock()
+        # reentrant: futures resolve under it (see _collect_loop), and a
+        # done-callback may submit or read stats() from the collector
+        self._lock = threading.RLock()
         self._lat: list = []
         self._batch_sizes: list = []
         self._t_first: Optional[float] = None
@@ -172,39 +187,47 @@ class DVFSService:
 
     def _dispatch_loop(self) -> None:
         max_batch = self.executor.max_batch
+        n_batch = 0
         while True:
             req = self._q.get()
             if req is _SHUTDOWN:
                 self._done.put(_SHUTDOWN)
                 return
+            n_batch += 1
             batch = [req]
             stop = False
-            deadline = time.perf_counter() + self.coalesce_s
-            while max_batch is None or len(batch) < max_batch:
-                remaining = deadline - time.perf_counter()
-                if remaining <= 0:
-                    break
-                try:
-                    nxt = self._q.get(timeout=remaining)
-                except queue.Empty:
-                    break
-                if nxt is _SHUTDOWN:
-                    stop = True
-                    break
-                batch.append(nxt)
+            with jax.profiler.TraceAnnotation("repro.service.coalesce",
+                                              batch=n_batch):
+                deadline = time.perf_counter() + self.coalesce_s
+                while max_batch is None or len(batch) < max_batch:
+                    remaining = deadline - time.perf_counter()
+                    if remaining <= 0:
+                        break
+                    try:
+                        nxt = self._q.get(timeout=remaining)
+                    except queue.Empty:
+                        break
+                    if nxt is _SHUTDOWN:
+                        stop = True
+                        break
+                    batch.append(nxt)
+            tags = {"batch": n_batch, "jobs": len(batch)}
             # double buffering: at most `depth` dispatched batches alive —
             # this acquire is the ONLY backpressure, and it waits on the
             # collector (host-side), never on the device directly
-            self._inflight.acquire()
+            with jax.profiler.TraceAnnotation("repro.service.admit", **tags):
+                self._inflight.acquire()
             try:
-                pending = self.executor.dispatch(
-                    [(r.program, r.axes) for r in batch])
+                with jax.profiler.TraceAnnotation("repro.service.dispatch",
+                                                  **tags):
+                    pending = self.executor.dispatch(
+                        [(r.program, r.axes) for r in batch])
             except Exception as e:  # bad request: fail the batch, move on
                 self._inflight.release()
                 for r in batch:
                     r.future.set_exception(e)
             else:
-                self._done.put((pending, batch))
+                self._done.put((pending, batch, tags))
             if stop:
                 self._done.put(_SHUTDOWN)
                 return
@@ -214,40 +237,50 @@ class DVFSService:
             item = self._done.get()
             if item is _SHUTDOWN:
                 return
-            pending, batch = item
+            pending, batch, tags = item
             pending: PendingGrid
             try:
-                traces = pending.block_until_ready().traces()
+                with jax.profiler.TraceAnnotation("repro.service.wait",
+                                                  **tags):
+                    pending.block_until_ready()
+                with jax.profiler.TraceAnnotation("repro.service.unpack",
+                                                  **tags):
+                    traces = pending.traces()
             except Exception as e:
                 for r in batch:
                     r.future.set_exception(e)
                 self._inflight.release()
                 continue
             self._inflight.release()
-            t_done = time.perf_counter()
-            lats = [t_done - r.t_submit for r in batch]
             with self._lock:
-                self._lat.extend(lats)
                 self._batch_sizes.append(len(batch))
-                self._t_last = t_done
-            for r, trs, lat in zip(batch, traces, lats):
-                res = {"traces": trs, "latency_s": lat,
-                       "batch_size": len(batch)}
-                if self.with_reports:
-                    epoch_us = float(r.axes.get(
-                        "epoch_us", self.static_cfg.epoch_us))
-                    res["report"] = point_report(
-                        trs, epoch_us, self.baseline, self.mechanism,
-                        self.static_cfg.power.n_freqs, r.telemetry)
-                r.future.set_result(res)
+            with jax.profiler.TraceAnnotation("repro.service.report", **tags):
+                for r, trs in zip(batch, traces):
+                    res = {"traces": trs, "batch_size": len(batch)}
+                    if self.with_reports:
+                        epoch_us = float(r.axes.get(
+                            "epoch_us", self.static_cfg.epoch_us))
+                        res["report"] = point_report(
+                            trs, epoch_us, self.baseline, self.mechanism,
+                            self.static_cfg.power.n_freqs, r.telemetry)
+                    # a job's latency ends once its caller can read the
+                    # result; holding the lock across resolve and stamp
+                    # keeps a caller woken by the future from reading
+                    # stats() without its own job
+                    with self._lock:
+                        r.future.set_result(res)
+                        t_done = time.perf_counter()
+                        self._lat.append(t_done - r.t_submit)
+                        self._t_last = t_done
 
     # ------------------------------------------------------------------
     # stats / lifecycle
     # ------------------------------------------------------------------
 
     def stats(self) -> Dict[str, float]:
-        """Sustained throughput + dispatch-latency percentiles over every
-        job resolved so far (latency = submit -> result ready)."""
+        """Sustained throughput + job-latency percentiles over every job
+        resolved so far (latency = ``submit`` -> report built and future
+        resolved)."""
         with self._lock:
             lat = np.asarray(self._lat, np.float64)
             sizes = list(self._batch_sizes)

@@ -5,6 +5,7 @@ invisible in results, oob requests must fail fast, and the async service
 must serve a stream with <= 2 fork-family compiles end to end."""
 import dataclasses
 import json
+import time
 from pathlib import Path
 
 import jax
@@ -150,18 +151,21 @@ def test_service_stream_two_fork_family_compiles_and_bitwise(progs):
 
 
 def test_service_async_api_and_lifecycle(progs):
-    """submit never blocks on the device, futures carry latency + report
-    with the request's own telemetry stats, stats() percentiles are
-    ordered, close() drains FIFO, and a closed service rejects submits."""
+    """submit never blocks on the device, futures carry the report with
+    the request's own telemetry stats, stats() latency percentiles are
+    ordered and inside the time the caller waited, close() drains FIFO,
+    and a closed service rejects submits."""
+    t0 = time.perf_counter()
     svc = DVFSService(SIM, max_batch=4, coalesce_s=0.001)
     futs = [svc.submit(progs["comd"], {"epoch_us": float(e)},
                        telemetry=[(i, 0.01 * (i + 1)) for i in range(3)])
             for e in (1.0, 2.0, 5.0)]
     assert not all(f.done() for f in futs)  # async: accept loop returned
     svc.close()  # drains: everything submitted above still resolves
+    waited = time.perf_counter() - t0
     for f in futs:
         res = f.result(timeout=60)
-        assert res["latency_s"] > 0 and 1 <= res["batch_size"] <= 4
+        assert 1 <= res["batch_size"] <= 4
         st = res["report"]["step_time"]
         assert st["n_steps"] == 3
         assert (st["first_step"], st["last_step"]) == (0, 2)
@@ -170,7 +174,7 @@ def test_service_async_api_and_lifecycle(progs):
     stats = svc.stats()
     assert stats["jobs"] == 3 and stats["jobs_per_sec"] > 0
     assert 0 < stats["p50_latency_s"] <= stats["p99_latency_s"] \
-        <= stats["max_latency_s"]
+        <= stats["max_latency_s"] < waited
     with pytest.raises(RuntimeError, match="closed"):
         svc.submit(progs["comd"])
     svc.close()  # idempotent
