@@ -92,10 +92,12 @@ Host spans: the layer writes ``jax.profiler.TraceAnnotation`` spans named
 ``repro.sweep.*`` on the profiler's clock, beside the device operations
 of a trace: ``prep`` (grid points through the flattened operands),
 ``dispatch`` (one executable family's carry build and enqueue), ``wait``
-(the fork family cut per mechanism, then every family's outputs ready),
-``unpack`` (the result dict)
-and ``copy`` (one channel array sliced and copied to the host). With no
-profiler running each costs a few microseconds.
+(every family's outputs ready), ``unpack`` (the result dict) and ``copy``
+(one family output array fetched to the host, whole). Results are cut on
+the host: each family output array crosses to the host once per call and
+every (row, mechanism, seed, epoch) trace is cut from it with numpy, so
+the cut enqueues no device program. With no profiler running each span
+costs a few microseconds.
 """
 from __future__ import annotations
 
@@ -128,22 +130,46 @@ from repro.core.workloads import Program
 STATIC_EXEC_AXES = MECH.get("static17").dedup_axes
 
 
-def _unpack_trace(arrs: Dict[str, jnp.ndarray], i: int, spec: MechanismSpec,
-                  squeeze_seed: bool,
-                  n_ep: Optional[int] = None) -> Dict[str, np.ndarray]:
-    """Cut flat-row ``i`` of a batch down to the ``run_sim`` trace schema:
-    squeeze the seed axis when it was implicit, slice the epoch axis to the
-    logical count (``None`` = full), and drop the ``hit_rate`` telemetry
-    channel for mechanisms whose spec doesn't declare it (the traced
-    family computes it for all; registered PC-family mechanisms get the
-    channel by setting ``hit_telemetry`` — no sweep-layer edit needed)."""
-    ep = slice(None) if n_ep is None else slice(None, n_ep)
+def _fetch(families: Sequence[Dict[str, jax.Array]]
+           ) -> List[Dict[str, np.ndarray]]:
+    """Every family output array on the host, fetched once each (one
+    ``repro.sweep.copy`` span per array): all transfers are started before
+    the first is read. A sharded array gathers its shards in its fetch."""
+    for arrs in families:
+        for v in arrs.values():
+            v.copy_to_host_async()
+    host = []
+    for arrs in families:
+        h = {}
+        for k, v in arrs.items():
+            with jax.profiler.TraceAnnotation("repro.sweep.copy"):
+                h[k] = np.asarray(v)
+        host.append(h)
+    return host
+
+
+def _unpack_trace(arrs: Dict[str, np.ndarray], i: int, spec: MechanismSpec,
+                  squeeze_seed: bool, n_ep: Optional[int] = None,
+                  mech: Optional[int] = None) -> Dict[str, np.ndarray]:
+    """Cut flat-row ``i`` of a family's host outputs (``_fetch``) down to
+    the ``run_sim`` trace schema: pick the mechanism ``mech`` of a fork
+    family's mechanism axis (``None`` for a specialised family, which has
+    none), squeeze the seed axis when it was implicit, slice the epoch axis
+    to the logical count (``None`` = full), and drop the ``hit_rate``
+    telemetry channel for mechanisms whose spec doesn't declare it (the
+    traced family computes it for all; registered PC-family mechanisms get
+    the channel by setting ``hit_telemetry`` — no sweep-layer edit needed).
+    Each array is a read-only view of the fetched array, C-contiguous as a
+    device slice copied to the host was (a copy only where a view is not)."""
+    idx = ((i, 0 if squeeze_seed else slice(None))
+           + (() if mech is None else (mech,))
+           + (slice(None) if n_ep is None else slice(None, n_ep),))
     tr = {}
     for k, v in arrs.items():
-        with jax.profiler.TraceAnnotation("repro.sweep.copy"):
-            tr[k] = np.asarray(v[i, 0, ep] if squeeze_seed else v[i, :, ep])
-    if not spec.hit_telemetry:
-        tr.pop("hit_rate", None)
+        if k == "hit_rate" and not spec.hit_telemetry:
+            continue
+        tr[k] = np.ascontiguousarray(v[idx])
+        tr[k].flags.writeable = False
     return tr
 
 # SimConfig fields that may vary across a grid without re-tracing (they map
@@ -557,12 +583,11 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
                                                  class_sims, dev), dev)
         return ops_cache[key]
 
-    # per-mechanism result row-lookup: name -> (arrays, class_of, n_classes)
-    by_mech: Dict[str, Tuple[Dict[str, jnp.ndarray], List[int], int]] = {}
-    # fork families' outputs, cut per mechanism once every family is
-    # enqueued: (outputs, mechanisms, class_of, n_classes)
-    fork_out: List[Tuple[Dict[str, jnp.ndarray], List[MechanismSpec],
-                         List[int], int]] = []
+    # every family's outputs, whole, and per mechanism where its rows lie:
+    # name -> (family index, mechanism index in a fork family or None,
+    # class_of, n_classes)
+    families: List[Dict[str, jax.Array]] = []
+    where: Dict[str, Tuple[int, Optional[int], List[int], int]] = {}
     no_ids = jnp.zeros((0,), jnp.int32)  # specialized mechs ignore mech_ids
 
     # Traced fork-family mechanisms share executables; group them by the
@@ -586,8 +611,9 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
         ops, dev = class_operands(class_of, class_sims)
         ids = jnp.asarray([SIM.FORK_MECH_IDS[s.name] for s in group],
                           jnp.int32)
-        fork_out.append((_run_family(st, dev, None, ops, seed_arr, ids),
-                         group, class_of, len(class_sims)))
+        for j, s in enumerate(group):
+            where[s.name] = (len(families), j, class_of, len(class_sims))
+        families.append(_run_family(st, dev, None, ops, seed_arr, ids))
 
     # Specialized families — static frequencies, oracle, and registered
     # custom mechanisms — compile their own executable and dedup the same
@@ -598,31 +624,23 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
             continue
         class_of, class_sims = classes_of(s)
         ops, dev = class_operands(class_of, class_sims)
-        ys = _run_family(st, dev, s, ops, seed_arr, no_ids)
-        by_mech[s.name] = (ys, class_of, len(class_sims))
+        where[s.name] = (len(families), None, class_of, len(class_sims))
+        families.append(_run_family(st, dev, s, ops, seed_arr, no_ids))
 
     with jax.profiler.TraceAnnotation("repro.sweep.wait"):
-        # The per-mechanism cuts are small device programs queued behind
-        # every family. A device holds a bounded number of programs in
-        # flight, so enqueued before the specialized families they held
-        # the host until the fork family ended; here the host waits on
-        # them as on the results.
-        for ys, group, class_of, C in fork_out:
-            for j, s in enumerate(group):
-                by_mech[s.name] = ({k: v[:, :, j] for k, v in ys.items()},
-                                   class_of, C)
-        jax.block_until_ready([arrs for arrs, _, _ in by_mech.values()])
+        jax.block_until_ready(families)
     out: Dict[tuple, Dict[str, Dict[str, Dict[str, np.ndarray]]]] = {}
     with jax.profiler.TraceAnnotation("repro.sweep.unpack"):
+        host = _fetch(families)
         for g, (key, sim_pt) in enumerate(zip(keys, sims)):
             out[key] = {}
             for w, name in enumerate(names_w):
                 trs = {}
                 for s in specs:
-                    arrs, class_of, C = by_mech[s.name]
-                    trs[s.name] = _unpack_trace(arrs, w * C + class_of[g], s,
-                                                squeeze_seed,
-                                                n_ep=sim_pt.n_epochs)
+                    f, j, class_of, C = where[s.name]
+                    trs[s.name] = _unpack_trace(host[f], w * C + class_of[g],
+                                                s, squeeze_seed,
+                                                sim_pt.n_epochs, j)
                 out[key][name] = trs
     return out
 
@@ -635,29 +653,34 @@ def run_grid(programs: Union[Dict[str, Program], Sequence[Program]],
 class PendingGrid:
     """The in-flight result of one :class:`GridExecutor` micro-batch.
 
-    Dispatch is asynchronous: this object holds the executables' device
-    arrays plus the row bookkeeping to cut them back into per-job
+    Dispatch is asynchronous: this object holds the executables' output
+    arrays, whole, plus the row bookkeeping to cut them back into per-job
     ``run_sim``-schema traces, and nothing here synchronizes with the
     device until ``block_until_ready``/``traces`` is called — the caller
     can keep preparing and dispatching later batches while this one
-    computes."""
+    computes. ``traces`` fetches each output array to the host once and
+    cuts every job from it there, so collecting a batch enqueues no device
+    program behind the batches still in flight."""
 
-    def __init__(self, rows, n_jobs: int):
-        # rows: per job, {mech_name: (arrays, flat_row, spec, n_ep)}
-        self._rows = rows
-        self.n_jobs = n_jobs
+    def __init__(self, families, mechs, jobs):
+        # families: the executables' output dicts; mechs: per mechanism,
+        # (spec, family index, mechanism index in a fork family or None);
+        # jobs: per real job, (flat row, logical n_epochs)
+        self._families = families
+        self._mechs = mechs
+        self._jobs = jobs
+        self.n_jobs = len(jobs)
 
     def block_until_ready(self) -> "PendingGrid":
-        for job in self._rows:
-            for arrs, _, _, _ in job.values():
-                jax.block_until_ready(arrs)
+        jax.block_until_ready(self._families)
         return self
 
     def traces(self) -> List[Dict[str, Dict[str, np.ndarray]]]:
         """Per-job ``{mechanism: trace}`` results (np arrays; blocks)."""
-        return [{m: _unpack_trace(arrs, i, spec, True, n_ep)
-                 for m, (arrs, i, spec, n_ep) in job.items()}
-                for job in self._rows]
+        host = _fetch(self._families)
+        return [{s.name: _unpack_trace(host[f], i, s, True, n_ep, j)
+                 for s, f, j in self._mechs}
+                for i, n_ep in self._jobs]
 
 
 class GridExecutor:
@@ -786,19 +809,19 @@ class GridExecutor:
             (stacked, p_log, axes_flat))
         ops = (stacked, p_log, axes_flat, n)
 
-        by_mech: Dict[str, Dict[str, jnp.ndarray]] = {}
+        families = []
+        where: Dict[str, Tuple[int, Optional[int]]] = {}
         if self._traced:
-            ys = _run_family(self._st, n_dev, None, ops, self._seed_arr,
-                             self._fork_ids)
-            for j, s in enumerate(self._traced):
-                by_mech[s.name] = {k: v[:, :, j] for k, v in ys.items()}
+            where.update({s.name: (0, j) for j, s in enumerate(self._traced)})
+            families.append(_run_family(self._st, n_dev, None, ops,
+                                        self._seed_arr, self._fork_ids))
         for s in self._special:
-            by_mech[s.name] = _run_family(self._st, n_dev, s, ops,
-                                          self._seed_arr, self._no_ids)
-
-        rows = [{s.name: (by_mech[s.name], j, s, sims[j].n_epochs)
-                 for s in self.specs} for j in range(n)]
-        return PendingGrid(rows, n)
+            where[s.name] = (len(families), None)
+            families.append(_run_family(self._st, n_dev, s, ops,
+                                        self._seed_arr, self._no_ids))
+        return PendingGrid(families,
+                           [(s, *where[s.name]) for s in self.specs],
+                           [(j, sims[j].n_epochs) for j in range(n)])
 
     def run(self, jobs: Sequence[Tuple[Program, dict]]
             ) -> List[Dict[str, Dict[str, np.ndarray]]]:

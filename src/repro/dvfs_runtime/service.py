@@ -43,8 +43,9 @@ closes; ``batch`` only), ``repro.service.admit`` (waiting for an
 in-flight slot) and ``repro.service.dispatch`` (staging and enqueue,
 holding the sweep layer's ``repro.sweep.dispatch``); the collector writes
 ``repro.service.wait`` (device results ready), ``repro.service.unpack``
-(per-job traces to the host, holding ``repro.sweep.copy``) and
-``repro.service.report`` (reports built, futures resolved).
+(the batch's output arrays fetched to the host, one ``repro.sweep.copy``
+each, and cut there into per-job traces) and ``repro.service.report``
+(reports built, futures resolved).
 """
 from __future__ import annotations
 

@@ -14,7 +14,7 @@ import pytest
 
 from repro.core import sweep as SW
 from repro.core.simulate import SimConfig, objective_weights, run_sim
-from repro.core.sweep import run_grid, run_suite
+from repro.core.sweep import GridExecutor, run_grid, run_suite
 from repro.core.workloads import get_workload, make_program
 
 SIM = SimConfig(n_cu=16, n_wf=12, n_epochs=48)
@@ -260,3 +260,105 @@ def test_axis_change_does_not_retrace(progs):
                  {"cap_per_ghz": 4000.0}):
         run_suite(progs, dataclasses.replace(SIM, **repl), ("pcstall",))
     assert dict(SW.TRACE_COUNTS) == before
+
+
+# A SimStatic no other test of this file compiles, so the compile-count
+# tests above still see their own first traces.
+CUT_SIM = SimConfig(n_cu=4, n_wf=6, n_epochs=16)
+
+
+def _padded_flat_operands(real):
+    """``_flat_operands`` padding the flat axis with at least one cycled
+    row, as a host with more devices pads it, on any device count."""
+    def flat_operands(stacked, p_logical, sims, n_dev):
+        progs, p_log, axes, n_flat = real(stacked, p_logical, sims, n_dev)
+        n_pad = -(-(n_flat + 1) // n_dev) * n_dev
+        return (SW._pad_flat(progs, n_pad), SW._pad_flat(p_log, n_pad),
+                SW._pad_flat(axes, n_pad), n_flat)
+    return flat_operands
+
+
+def _run_cut_case(case, progs):
+    """Run one case's grid or executor batch; returns how many traces it
+    must hand back (one per real row and mechanism)."""
+    three = {**progs, "small": make_program("small", "phased", 5, P=256)}
+    if case == "dedup_broadcast":  # static17: one class for two objectives
+        run_grid(progs, CUT_SIM, {"objective": ["ed2p", "edp"]},
+                 ("static17", "pcstall"))
+        return len(progs) * 2 * 2
+    if case == "masked_tail":
+        run_grid(progs, CUT_SIM, [{"epoch_us": 1.0, "n_epochs": 8},
+                                  {"epoch_us": 10.0, "n_epochs": 16}],
+                 ("static17", "pcstall", "crisp"))
+        return len(progs) * 2 * 3
+    if case == "seed_axis":
+        run_grid(progs, CUT_SIM, {"objective": ["ed2p"]},
+                 ("crisp", "pcstall", "oracle"), seeds=[0, 3])
+        return len(progs) * 3
+    if case == "odd_flat_pad":
+        run_grid(three, CUT_SIM, {"epoch_us": [1.0]}, ("pcstall", "crisp"))
+        return len(three) * 2
+    assert case == "executor_small_batch"
+    jobs = [(three["comd"], {"epoch_us": 1.0}),
+            (three["small"], {"epoch_us": 10.0, "n_epochs": 8}),
+            (three["xsbench"], {"objective": "edp"})]
+    traces = GridExecutor(CUT_SIM, ("pcstall", "static17"),
+                          buckets=(8,)).run(jobs)
+    assert len(traces) == len(jobs)
+    return len(jobs) * 2
+
+
+@pytest.mark.parametrize("case", ["dedup_broadcast", "masked_tail",
+                                  "seed_axis", "odd_flat_pad",
+                                  "executor_small_batch"])
+def test_host_cut_matches_device_cut(progs, monkeypatch, case):
+    """Every trace the sweep layer returns, cut on the host from its
+    family's fetched output array, equals bit for bit the device slice
+    ``v[i, :, :n_ep]`` (the fork family's ``v[:, :, j]`` first, the seed
+    axis squeezed where it was implicit) of the same executable outputs
+    copied to the host: same keys, dtype, shape and bytes, read-only and
+    C-contiguous. Pad rows and cycled bucket rows are never cut."""
+    real_rows, dev_of, cuts = {}, [], []
+    real_run, real_fetch, real_cut = \
+        SW._run_family, SW._fetch, SW._unpack_trace
+
+    def run_family(st, n_dev, mechanism, operands, seed_arr, ids):
+        ys = real_run(st, n_dev, mechanism, operands, seed_arr, ids)
+        real_rows[id(ys)] = (ys, operands[3])
+        return ys
+
+    def fetch(families):
+        host = real_fetch(families)
+        dev_of.extend(zip(host, families))
+        return host
+
+    def cut(arrs, i, spec, squeeze_seed, n_ep=None, mech=None):
+        tr = real_cut(arrs, i, spec, squeeze_seed, n_ep, mech)
+        dev = next(d for h, d in dev_of if h is arrs)
+        cuts.append((dev, i, spec, squeeze_seed, n_ep, mech, tr))
+        return tr
+
+    monkeypatch.setattr(SW, "_run_family", run_family)
+    monkeypatch.setattr(SW, "_fetch", fetch)
+    monkeypatch.setattr(SW, "_unpack_trace", cut)
+    if case == "odd_flat_pad":
+        monkeypatch.setattr(SW, "_flat_operands",
+                            _padded_flat_operands(SW._flat_operands))
+    n_traces = _run_cut_case(case, progs)
+    assert len(cuts) == n_traces
+    assert any(mech is not None for *_, mech, _ in cuts)
+    if case == "odd_flat_pad":
+        assert all(next(iter(d.values())).shape[0] > real_rows[id(d)][1]
+                   for _, d in dev_of)
+    for dev, i, spec, squeeze, n_ep, mech, tr in cuts:
+        assert i < real_rows[id(dev)][1], "a pad row was cut"
+        assert list(tr) == [k for k in dev
+                            if spec.hit_telemetry or k != "hit_rate"]
+        for k, got in tr.items():
+            v = dev[k] if mech is None else dev[k][:, :, mech]
+            want = np.asarray(v[i, 0, :n_ep] if squeeze else v[i, :, :n_ep])
+            ctx = f"{case}/{spec.name}/row {i}/{k}"
+            assert (got.dtype, got.shape) == (want.dtype, want.shape), ctx
+            assert got.tobytes() == want.tobytes(), ctx
+            assert not got.flags.writeable, ctx
+            assert got.flags.c_contiguous, ctx

@@ -2,7 +2,7 @@
 trace around a tiny ``run_grid`` and a tiny ``DVFSService`` stream holds
 each ``repro.*`` span on the thread that does the work, nested as the
 per-layer metrics read them (``bench/metrics/``), with one copy span per
-channel array; and the compiled grid executable names each epoch phase
+family output array fetched to the host; and the compiled grid executable names each epoch phase
 in its ops' ``op_name`` metadata."""
 import collections
 import glob
@@ -22,11 +22,12 @@ from repro.dvfs_runtime.service import DVFSService
 SIM_CFG = SimConfig(n_cu=4, n_wf=8, n_epochs=16)
 WORKLOADS = ("comd", "FwdSoft")
 # one traced fork-family mechanism without hit telemetry, one with it, and
-# one static: the fork family's executable emits six channels per row
-# (hit_rate included, dropped on unpack where the spec declines it), a
-# static's five
+# one static: the two traced ones share the fork family's executable, which
+# emits six channel arrays (hit_rate included, dropped on unpack where the
+# spec declines it), and the static its own five. Each array is fetched to
+# the host once per call, whatever the rows in it.
 MECHS = ("crisp", "pcstall", "static17")
-CHANNELS = {"crisp": 6, "pcstall": 6, "static17": 5}
+FAMILY_ARRAYS = 6 + 5
 
 
 @pytest.fixture(scope="module")
@@ -95,7 +96,7 @@ def test_sweep_spans_on_the_callers_thread(progs, tmp_path):
     assert call_s <= prep[0][0] and unpack[1] <= call_e
     copies = _named(evs, "repro.sweep.copy")
     assert _inside(copies, [unpack])
-    assert len(copies) == len(WORKLOADS) * sum(CHANNELS.values())
+    assert len(copies) == FAMILY_ARRAYS
 
 
 def test_service_spans_on_dispatcher_and_collector(progs, tmp_path):
@@ -143,9 +144,12 @@ def test_service_spans_on_dispatcher_and_collector(progs, tmp_path):
     unpack = _named(c, "repro.service.unpack")
     copies = _named(c, "repro.sweep.copy")
     assert _inside(copies, unpack)
-    # each real job's rows, both mechanisms: pcstall rides the fork
-    # family's six channels, static17 its own five
-    assert len(copies) == len(jobs) * (6 + 5)
+    # each batch fetches its families' arrays once, whatever its job
+    # count: pcstall's fork family has six, static17's own family five
+    for s, e, st in unpack:
+        assert sum(s <= cs and ce <= e for cs, ce, _ in copies) \
+            == FAMILY_ARRAYS, st
+    assert len(copies) == len(unpack) * FAMILY_ARRAYS
 
 
 @pytest.mark.parametrize("use_pallas,scopes", [
