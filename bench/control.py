@@ -61,7 +61,8 @@ def main():
         if d is not None and hasattr(d, "reseed"):
             d.reseed(seed)
         else:
-            d = cell.kind.Runner(cell.cfg, cell.mix, n_dev=cell.chips)
+            d = cell.kind.Runner(cell.cfg, cell.mix, cell.library,
+                                 n_dev=cell.chips)
             d.setup(seed)
         d.window(args.seconds, quiet)
         if not hasattr(d, "reseed"):

@@ -3,8 +3,15 @@ found by name under the benchmark directory:
 
 * ``workloads/<cell>.json``  the cell: its configuration, traffic mix, chips;
 * ``configs/<config>.json``  the deployment, as it is run;
+* ``programs/<library>.py``  the program library the deployment names
+                             under ``"programs"``: ``NAMES`` and
+                             ``arrays(name, cfg)``;
 * ``traffic/<mix>.json``     the traffic mix: its kind and parameters;
-* ``traffic/<kind>.py``      the generator and runner of that kind;
+* ``traffic/<kind>.py``      the generator and runner of that kind:
+                             ``END_TO_END``, the metrics it reports,
+                             ``program_names(cfg, mix)``, the programs it
+                             draws, and ``Runner(cfg, mix, library,
+                             n_dev)``;
 * ``metrics/<metric>.py``    one per-layer metric's reader.
 
 A run loads the cell, sets the deployment up (inputs, compilation, one warm
@@ -61,8 +68,12 @@ def load_cell(name: str, bench: Path = BENCH) -> SimpleNamespace:
     mix = json.loads((bench / "traffic" / f"{cell['traffic']}.json")
                      .read_text())
     kind = load_module(bench / "traffic" / f"{mix['kind']}.py")
+    if "programs" not in cfg:
+        raise KeyError(f"configuration {cell['config']!r} names no program "
+                       "library under \"programs\"")
+    library = load_module(bench / "programs" / f"{cfg['programs']}.py")
     return SimpleNamespace(name=name, chips=int(cell["chips"]), cfg=cfg,
-                           mix=mix, kind=kind, bench=bench)
+                           mix=mix, kind=kind, library=library, bench=bench)
 
 
 def readers(bench: Path = BENCH) -> Dict[str, object]:
@@ -168,7 +179,8 @@ def run(argv: Optional[List[str]] = None, bench: Path = BENCH,
     use_cache(bench.parent)
     builds = BuildCounter()
 
-    runner = cell.kind.Runner(cell.cfg, cell.mix, n_dev=cell.chips)
+    runner = cell.kind.Runner(cell.cfg, cell.mix, cell.library,
+                              n_dev=cell.chips)
     runner.setup(args.seed)
     setup_s = time.perf_counter() - T_START
     log(f"bench: {cell.name} on {len(devs)} x {devs[0].device_kind}, "
@@ -189,6 +201,11 @@ def run(argv: Optional[List[str]] = None, bench: Path = BENCH,
     builds.on = False
     if args.trace:
         jax.profiler.stop_trace()
+    elif set(e2e) | {"setup_s"} != set(cell.kind.END_TO_END):
+        raise RuntimeError(
+            f"kind {cell.mix['kind']!r} declares END_TO_END "
+            f"{sorted(cell.kind.END_TO_END)}, its window reported "
+            f"{sorted(e2e)} and setup_s")
     log(f"bench: programs built inside the window: {builds.n}")
     for line in runner.notes():
         log(f"bench: {line}")

@@ -33,7 +33,7 @@ def main():
     harness.use_cache(harness.ROOT)
     for rate in args.rates:
         mix = dict(cell.mix, rate_per_s=rate)
-        d = cell.kind.Runner(cell.cfg, mix, n_dev=cell.chips)
+        d = cell.kind.Runner(cell.cfg, mix, cell.library, n_dev=cell.chips)
         d.setup(args.seed)
         e2e = d.window(args.seconds, lambda name: contextlib.nullcontext())
         d.close()

@@ -1,6 +1,7 @@
-"""Time from the end of a call's last grid executable on a device to the
-call's return: device-to-host copies and the sweep layer's per-row unpack
-(``core/sweep.py`` ``_unpack_trace``). Mean over the calls of the window."""
+"""Time from the end of a call's last grid executable on any device to the
+call's return: the sweep layer's fetch of each family output array to the
+host (``core/sweep.py`` ``_fetch``) and its cut of every row there
+(``_unpack_trace``). Mean over the calls of the window."""
 UNIT = "ms"
 
 

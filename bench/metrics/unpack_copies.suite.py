@@ -1,6 +1,7 @@
-"""Channel arrays the sweep layer slices and copies to the host per call
-(``core/sweep.py`` ``_unpack_trace``, one ``repro.sweep.copy`` span
-each). Mean over the calls of the window."""
+"""Executable-family output arrays the sweep layer fetches to the host per
+call (``core/sweep.py`` ``_fetch``, one ``repro.sweep.copy`` span each;
+a sharded array gathers its shards in its one fetch). Mean over the calls
+of the window."""
 from bench import spans as S
 
 UNIT = "copies"

@@ -1,9 +1,9 @@
 """The one place where the benchmark builds the program's own objects: the
 simulator's configuration and its ``Program`` inputs, from a deployment
-file and the benchmark's generated arrays."""
+file and the per-block arrays of its program library."""
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Iterable
 
 import numpy as np
 
@@ -20,6 +20,27 @@ def sim_config(cfg: dict, seed: int):
     from repro.core.simulate import SimConfig
     return SimConfig(**{k: cfg[k] for k in SIM_FIELDS},
                      power=PowerConfig(**cfg["power"]), seed=int(seed))
+
+
+def program_arrays(library, cfg: dict, names: Iterable[str]
+                   ) -> Dict[str, Dict[str, np.ndarray]]:
+    """``{name: per-block arrays}`` of programs ``names`` from the
+    deployment's program library (``programs/<cfg["programs"]>.py``, a
+    module with ``NAMES`` and ``arrays(name, cfg)``): float32 ``i0``,
+    ``sens`` and ``mem`` of one length each."""
+    out = {}
+    for name in names:
+        if name not in library.NAMES:
+            raise KeyError(f"program {name!r} is not in library "
+                           f"{cfg['programs']!r}: {library.NAMES}")
+        a = library.arrays(name, cfg)
+        cols = [np.asarray(a[k]) for k in ("i0", "sens", "mem")]
+        if any(c.dtype != np.float32 or c.ndim != 1
+               or c.shape != cols[0].shape for c in cols):
+            raise ValueError(f"library {cfg['programs']!r} gave {name!r} "
+                             "arrays that are not float32 of one length")
+        out[name] = a
+    return out
 
 
 def programs(arrays: Dict[str, Dict[str, np.ndarray]]):

@@ -1,19 +1,25 @@
 """``BENCHMARK.json`` and the benchmark's files agree: every cell, its
-configuration and traffic mix, every per-layer metric's reader and every
-end-to-end metric a traffic kind reports are found by their names."""
+configuration and traffic mix, every program library and the programs its
+cells draw, every per-layer metric's reader and every end-to-end metric a
+traffic kind declares are found by their names."""
 import json
 import re
 from pathlib import Path
 
 import pytest
 
+from bench import harness
+
 ROOT = Path(__file__).resolve().parents[2]
 BENCH = ROOT / "bench"
 SPEC = json.loads((ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
-E2E_OF_KIND = {"sweep": {"cu_epochs_per_s", "setup_s"},
-               "stream": {"jobs_per_s", "job_p95_ms", "setup_s"}}
+KINDS = sorted(p.stem for p in (BENCH / "traffic").glob("*.py"))
+
+
+def _kind(name):
+    return harness.load_module(BENCH / "traffic" / f"{name}.py")
 
 
 def test_top_level_keys():
@@ -32,7 +38,7 @@ def test_cell_file_matches(cell):
     assert (BENCH / "traffic" / f"{mix['kind']}.py").is_file()
     reported = {m["name"] for m in SPEC["end_to_end"]
                 if cell["name"] in m.get("workloads", [cell["name"]])}
-    assert reported == E2E_OF_KIND[mix["kind"]]
+    assert reported == set(_kind(mix["kind"]).END_TO_END)
     assert len(cell["why"]) <= 200 and NAME.match(cell["name"])
 
 
@@ -42,6 +48,28 @@ def test_config_file_states_its_cuts(cfg):
     assert cfg["file"] == f"bench/configs/{cfg['name']}.json"
     assert sorted(f["reduced"]) == sorted(cfg["reduced"])
     assert f["source"] == cfg["source"] and "assumed" in f
+
+
+@pytest.mark.parametrize("cfg", SPEC["configs"], ids=lambda c: c["name"])
+def test_config_library_holds_its_cells_programs(cfg):
+    f = json.loads((ROOT / cfg["file"]).read_text())
+    lib_file = BENCH / "programs" / f"{f['programs']}.py"
+    assert lib_file.is_file()
+    lib = harness.load_module(lib_file)
+    cells = [c for c in SPEC["workloads"] if c["config"] == cfg["name"]]
+    assert cells
+    for c in cells:
+        mix = json.loads((BENCH / "traffic" / f"{c['traffic']}.json")
+                         .read_text())
+        drawn = _kind(mix["kind"]).program_names(f, mix)
+        assert drawn and set(drawn) <= set(lib.NAMES), c["name"]
+
+
+@pytest.mark.parametrize("kind", KINDS)
+def test_kind_declares_its_end_to_end_metrics(kind):
+    declared = _kind(kind).END_TO_END
+    assert "setup_s" in declared and len(set(declared)) == len(declared)
+    assert set(declared) <= {m["name"] for m in SPEC["end_to_end"]}
 
 
 @pytest.mark.parametrize("metric", SPEC["per_layer"], ids=lambda m: m["name"])

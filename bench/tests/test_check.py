@@ -20,7 +20,7 @@ SUITE_LIMITS = json.loads((harness.BENCH / "traffic/suite.json")
 def sample(tmp_path_factory):
     tree = tiny.make_tree(tmp_path_factory.mktemp("tree"))
     cell = harness.load_cell("tiny_suite", tree)
-    r = cell.kind.Runner(cell.cfg, cell.mix, n_dev=1)
+    r = cell.kind.Runner(cell.cfg, cell.mix, cell.library, n_dev=1)
     r.setup(2**31 + 11)
     r.window(0.01, lambda name: contextlib.nullcontext())
     rows, traces, _ = r.sample()

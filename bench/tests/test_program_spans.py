@@ -27,8 +27,9 @@ SUITE = ("sweep_prep_ms.suite", "sweep_wait_ms.suite",
 STREAM = ("svc_admit_ms.stream", "svc_dispatch_ms.stream",
           "svc_collect_ms.stream")
 DEVICE = ("idle_host_share.stream",)
-# channel arrays copied per row: the fork family's six for its 7 traced
-# mechanisms, five for each of the 3 statics and oracle
+# channel arrays copied per row in the recording (a program that cut each
+# row on the device): the fork family's six for its 7 traced mechanisms,
+# five for each of the 3 statics and oracle
 COPIES_PER_WORKLOAD = 7 * 6 + 4 * 5
 
 
@@ -48,12 +49,23 @@ def _traced(tree, monkeypatch, cell, seed, seconds):
 
 
 def test_sweep_span_metrics_read_on_cpu(tree, monkeypatch):
+    from repro.core import sweep as SW
+    fetch, fetched = SW._fetch, []
+
+    def counting_fetch(families):
+        fetched.append(sum(len(arrs) for arrs in families))
+        return fetch(families)
+    monkeypatch.setattr(SW, "_fetch", counting_fetch)
     res, tr = _traced(tree, monkeypatch, "tiny_suite", str(2**31 + 3), "0.2")
     m = res["metrics"]
     for name in SUITE:
         assert m[name]["value"] > 0, name
-    assert m["unpack_copies.suite"] == {
-        "value": 2 * COPIES_PER_WORKLOAD, "unit": "copies"}
+    # one copy span per executable-family output array of a call: the fork
+    # family's six for its 7 traced mechanisms, five for each of the 3
+    # statics and oracle, whatever the workloads
+    assert len(set(fetched)) == 1 and fetched[0] == 6 + 4 * 5
+    assert m["unpack_copies.suite"] == {"value": fetched[0],
+                                        "unit": "copies"}
     calls = tr.span("call")
     assert calls
     for lo, hi in calls:

@@ -31,10 +31,17 @@ from bench import check as CHK
 from bench import gen
 from bench import program as PRG
 
+END_TO_END = ("jobs_per_s", "job_p95_ms", "setup_s")
+
+
+def program_names(cfg: dict, mix: dict) -> List[str]:
+    """The programs a cell of this kind draws from its library."""
+    return list(mix["workloads"])
+
 
 class Runner:
-    def __init__(self, cfg: dict, mix: dict, n_dev: int):
-        self.cfg, self.mix, self.n_dev = cfg, mix, n_dev
+    def __init__(self, cfg: dict, mix: dict, library, n_dev: int):
+        self.cfg, self.mix, self.library, self.n_dev = cfg, mix, library, n_dev
         self.attempted = self.failed = 0
         self.svc = None
         self.jobs: List[dict] = []
@@ -50,8 +57,8 @@ class Runner:
         from repro.dvfs_runtime.service import DVFSService
         svc = self.cfg["service"]
         self.seed = seed
-        self.arrays = {w: gen.program_arrays(w, self.cfg["program_blocks"])
-                       for w in self.mix["workloads"]}
+        self.arrays = PRG.program_arrays(self.library, self.cfg,
+                                         program_names(self.cfg, self.mix))
         self.progs = PRG.programs(self.arrays)
         self.sim = PRG.sim_config(self.cfg, seed)
         self.svc = DVFSService(

@@ -21,15 +21,20 @@ from typing import List
 import numpy as np
 
 from bench import check as CHK
-from bench import gen
 from bench import program as PRG
 
+END_TO_END = ("cu_epochs_per_s", "setup_s")
 CHANNELS = ("work", "energy", "fidx")
 
 
+def program_names(cfg: dict, mix: dict) -> List[str]:
+    """The programs a cell of this kind draws from its library."""
+    return list(cfg["workloads"])
+
+
 class Runner:
-    def __init__(self, cfg: dict, mix: dict, n_dev: int):
-        self.cfg, self.mix, self.n_dev = cfg, mix, n_dev
+    def __init__(self, cfg: dict, mix: dict, library, n_dev: int):
+        self.cfg, self.mix, self.library, self.n_dev = cfg, mix, library, n_dev
         self.attempted = self.failed = 0
         self.calls: List[tuple] = []
         self.results: List[dict] = []
@@ -37,8 +42,8 @@ class Runner:
     def setup(self, seed: int) -> None:
         from repro.core import sweep as SW
         self.seed = seed
-        self.arrays = {w: gen.program_arrays(w, self.cfg["program_blocks"])
-                       for w in self.cfg["workloads"]}
+        self.arrays = PRG.program_arrays(self.library, self.cfg,
+                                         program_names(self.cfg, self.mix))
         self.progs = PRG.programs(self.arrays)
         self.sim = PRG.sim_config(self.cfg, seed)
         self.mechs = tuple(self.cfg["mechanisms"])
