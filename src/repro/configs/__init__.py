@@ -1,5 +1,7 @@
 """Architecture registry. ``get_config(name)`` returns the full published config;
-``get_smoke_config(name)`` a reduced same-family config for CPU tests."""
+``get_smoke_config(name)`` a reduced same-family config for CPU tests.
+``ARCH_IDS`` are the architectures the model substrate builds; the registry
+also serves ``deepseek-v3`` to the DVFS step path."""
 from __future__ import annotations
 
 import importlib
@@ -46,6 +48,8 @@ _MODULES: Dict[str, str] = {
     "rwkv6-3b": "rwkv6_3b",
     "qwen2-moe-a2.7b": "qwen2_moe_a2_7b",
     "granite-moe-1b-a400m": "granite_moe_1b_a400m",
+    # the DVFS step path only (no MLA in the model substrate)
+    "deepseek-v3": "deepseek_v3",
 }
 
 
@@ -57,7 +61,11 @@ def get_config(name: str) -> ModelConfig:
 
 
 def get_smoke_config(name: str) -> ModelConfig:
-    return reduced(get_config(name))
+    """A config module's own ``SMOKE`` where it has one, else
+    ``reduced(CONFIG)``."""
+    cfg = get_config(name)
+    mod = importlib.import_module(f"repro.configs.{_MODULES[name]}")
+    return getattr(mod, "SMOKE", None) or reduced(cfg)
 
 
 def all_configs() -> Dict[str, ModelConfig]:

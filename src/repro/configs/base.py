@@ -26,6 +26,13 @@ class MoEConfig:
     router_jitter: float = 0.0
     # load-balancing aux loss weight
     aux_loss_weight: float = 0.01
+    # routed weights scaled by this (DeepSeek-V3: after normalising its
+    # top-k sigmoid scores)
+    routed_scaling_factor: float = 1.0
+    # group-limited routing: experts split into ``n_group`` groups, a
+    # token's top-k drawn from its best ``topk_group`` groups (0: none)
+    n_group: int = 0
+    topk_group: int = 0
 
 
 @dataclass(frozen=True)
@@ -60,6 +67,28 @@ class ModelConfig:
     dtype: str = "bfloat16"
     # remat policy: 'none' | 'full' | 'dots'
     remat: str = "full"
+    # multi-head latent attention (MLA; 0 = GQA attention): queries through
+    # a ``q_lora_rank`` bottleneck (0: one projection), keys and values
+    # through a ``kv_lora_rank`` latent shared by every head; each head's
+    # query/key is ``qk_nope_head_dim`` plus a ``qk_rope_head_dim`` RoPE
+    # part whose key is shared by the heads, its value ``v_head_dim``
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # leading layers with a dense SwiGLU FFN of width ``d_ff`` before the
+    # MoE layers begin (0: MoE on every layer of an MoE config)
+    first_k_dense_replace: int = 0
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def n_moe_layers(self) -> int:
+        return self.n_layers - self.first_k_dense_replace \
+            if self.moe is not None else 0
 
     @property
     def resolved_head_dim(self) -> int:
@@ -82,7 +111,18 @@ class ModelConfig:
         emb = self.vocab * d
         head = 0 if self.tie_embeddings else self.vocab * d
         per_layer = 0
-        if self.attn_kind != "none" and self.n_heads:
+        if self.mla:
+            H, qk = self.n_heads, self.qk_nope_head_dim + self.qk_rope_head_dim
+            kr = self.kv_lora_rank
+            if self.q_lora_rank:
+                per_layer += d * self.q_lora_rank + self.q_lora_rank \
+                    + self.q_lora_rank * H * qk
+            else:
+                per_layer += d * H * qk
+            per_layer += d * (kr + self.qk_rope_head_dim) + kr
+            per_layer += kr * H * (self.qk_nope_head_dim + self.v_head_dim)
+            per_layer += H * self.v_head_dim * d
+        elif self.attn_kind != "none" and self.n_heads:
             q = d * self.n_heads * hd
             kv = 2 * d * self.n_kv_heads * hd
             o = self.n_heads * hd * d
@@ -90,15 +130,17 @@ class ModelConfig:
         if self.family in ("ssm",) or (self.ssm is not None and self.family == "hybrid"):
             # rwkv/mamba mixing params approx: 4 d^2-ish
             per_layer += 4 * d * d
+        per_layer += 2 * d  # norms
+        n_moe = self.n_moe_layers
         if self.moe is not None:
             e = self.moe
-            per_layer += e.num_experts * 3 * d * e.expert_d_ff
-            per_layer += e.num_shared * 3 * d * (e.shared_d_ff or e.expert_d_ff)
-            per_layer += d * e.num_experts  # router
+            moe = e.num_experts * 3 * d * e.expert_d_ff
+            moe += e.num_shared * 3 * d * (e.shared_d_ff or e.expert_d_ff)
+            moe += d * e.num_experts  # router
         else:
-            per_layer += 3 * d * self.d_ff  # swiglu
-        per_layer += 2 * d  # norms
-        return emb + head + L * per_layer
+            moe = 0
+        dense = 3 * d * self.d_ff  # swiglu
+        return emb + head + L * per_layer + n_moe * moe + (L - n_moe) * dense
 
     @property
     def n_active_params(self) -> int:
@@ -106,10 +148,10 @@ class ModelConfig:
         if self.moe is None:
             return self.n_params
         e = self.moe
-        d, L = self.d_model, self.n_layers
+        d = self.d_model
         routed_all = e.num_experts * 3 * d * e.expert_d_ff
         routed_active = e.top_k * 3 * d * e.expert_d_ff
-        return self.n_params - L * (routed_all - routed_active)
+        return self.n_params - self.n_moe_layers * (routed_all - routed_active)
 
 
 # ---------------------------------------------------------------------------
@@ -123,6 +165,8 @@ class ShapeConfig:
     seq_len: int
     global_batch: int
     kind: str  # 'train' | 'prefill' | 'decode'
+    # prefill: tokens already in the cache ahead of the ``seq_len`` new ones
+    prefix: int = 0
 
 
 TRAIN_4K = ShapeConfig("train_4k", 4_096, 256, "train")

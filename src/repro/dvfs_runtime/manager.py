@@ -22,7 +22,8 @@ from repro.core.mechanisms import MechanismSpec
 from repro.core.simulate import SimConfig, ednp, prediction_accuracy
 from repro.core.sweep import GridExecutor
 from repro.core.workloads import Program
-from repro.dvfs_runtime.telemetry import arch_program
+from repro.dvfs_runtime.telemetry import (DEFAULT_DEPLOYMENT, Deployments,
+                                          arch_program, unit)
 
 Mechanism = Union[str, MechanismSpec]
 
@@ -93,10 +94,13 @@ class DVFSManager:
 
     @classmethod
     def for_model(cls, cfg: ModelConfig, shape: ShapeConfig,
+                  dep: Deployments = DEFAULT_DEPLOYMENT,
                   objective: str = "ed2p", n_cu: int = 16,
                   mechanism: Mechanism = "pcstall",
                   baseline: Mechanism = "static17") -> "DVFSManager":
-        prog = arch_program(cfg, shape)
+        """The manager of ``cfg``'s ``shape`` step on the unit of ``dep``
+        that runs steps of ``shape.kind``."""
+        prog = arch_program(cfg, shape, unit(dep, shape.kind))
         sim = SimConfig(n_cu=n_cu, n_epochs=400, objective=objective)
         return cls(program=prog, sim=sim, mechanism=mechanism,
                    baseline=baseline)

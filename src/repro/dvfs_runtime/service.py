@@ -30,16 +30,23 @@ turns the sweep substrate into that service:
   reports (``repro.dvfs_runtime.manager.point_report``) and resolves the
   futures.
 
+* a service built by ``for_model`` also takes model steps
+  (``submit_step``): the dispatcher derives each step's program from the
+  model and the deployment (``telemetry.step_program``), once per
+  distinct step, and stages it as it stages a submitted program.
+
 ``stats()`` reports sustained jobs/sec and job-latency percentiles, each
 job timed from ``submit`` until its report is built and its future
 resolved — the ``serve_stream`` benchmark record is built from exactly
-these counters.
+these counters — and the programs derived so far.
 
 Host spans (``jax.profiler.TraceAnnotation``, on the profiler's clock, a
 few microseconds each with no profiler running), each tagged with the
 batch number ``batch`` and its real job count ``jobs``: the dispatcher
 writes ``repro.service.coalesce`` (first request taken until the batch
-closes; ``batch`` only), ``repro.service.admit`` (waiting for an
+closes; ``batch`` only), ``repro.service.derive`` (a batch's steps turned
+into programs; also ``misses``, the programs not found in the cache and
+derived), ``repro.service.admit`` (waiting for an
 in-flight slot) and ``repro.service.dispatch`` (staging and enqueue,
 holding the sweep layer's ``repro.sweep.dispatch``); the collector writes
 ``repro.service.wait`` (device results ready), ``repro.service.unpack``
@@ -59,14 +66,15 @@ from typing import Dict, Iterable, Optional, Sequence, Tuple, Union
 import jax
 import numpy as np
 
-from repro.configs.base import ModelConfig, ShapeConfig
+from repro.configs.base import ModelConfig
 from repro.core import mechanisms as MECH
 from repro.core.mechanisms import MechanismSpec
 from repro.core.simulate import SimConfig
 from repro.core.sweep import GridExecutor, PendingGrid
 from repro.core.workloads import Program
+from repro.dvfs_runtime import telemetry as TEL
 from repro.dvfs_runtime.manager import StepLog, point_report
-from repro.dvfs_runtime.telemetry import arch_program
+from repro.dvfs_runtime.telemetry import DEFAULT_DEPLOYMENT, Deployments, Step
 
 Mechanism = Union[str, MechanismSpec]
 
@@ -75,7 +83,7 @@ _SHUTDOWN = object()
 
 @dataclasses.dataclass
 class _Request:
-    program: Program
+    program: Union[Program, Step]  # a Step until the dispatcher derives it
     axes: dict
     telemetry: Tuple[Tuple[int, float], ...]
     future: Future
@@ -123,6 +131,11 @@ class DVFSService:
         self.coalesce_s = coalesce_s
         self.depth = depth
         self.with_reports = with_reports
+        # the model and deployment whose steps ``submit_step`` takes
+        # (``for_model``), and the programs derived from them, by step
+        self.model: Optional[ModelConfig] = None
+        self.deployment: Deployments = DEFAULT_DEPLOYMENT
+        self._programs: Dict[Step, tuple] = {}
 
         self._q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._done: "queue.SimpleQueue" = queue.SimpleQueue()
@@ -143,14 +156,20 @@ class DVFSService:
         self._collector.start()
 
     @classmethod
-    def for_model(cls, cfg: ModelConfig, shape: ShapeConfig,
+    def for_model(cls, cfg: ModelConfig,
+                  dep: Deployments = DEFAULT_DEPLOYMENT,
                   objective: str = "ed2p", n_cu: int = 16,
+                  sim: Optional[SimConfig] = None,
                   **kw) -> "DVFSService":
-        """Service sized like ``DVFSManager.for_model`` — same SimConfig,
-        so a decode loop's requests ride the manager's numerics."""
-        sim = SimConfig(n_cu=n_cu, n_epochs=400, objective=objective)
+        """A service for the steps of model ``cfg`` on deployment ``dep``
+        (one :class:`~repro.dvfs_runtime.telemetry.Deployment`, or one per
+        step kind), sized like ``DVFSManager.for_model`` — same SimConfig,
+        so a decode loop's requests ride the manager's numerics — unless
+        ``sim`` is given."""
+        if sim is None:
+            sim = SimConfig(n_cu=n_cu, n_epochs=400, objective=objective)
         svc = cls(sim, **kw)
-        svc.default_program = arch_program(cfg, shape)
+        svc.model, svc.deployment = cfg, dep
         return svc
 
     # ------------------------------------------------------------------
@@ -174,6 +193,41 @@ class DVFSService:
                 program, dict(axes or {}),
                 tuple((int(s), float(t)) for s, t in telemetry), fut, now))
         return fut
+
+    def submit_step(self, step: Step, axes: Optional[dict] = None,
+                    telemetry: StepLog = ()) -> Future:
+        """Enqueue one request for a step of the service's model: the
+        dispatcher derives the step's program (once per distinct step)
+        and stages it as ``submit`` stages a program."""
+        if self.model is None:
+            raise RuntimeError("submit_step needs a service built by "
+                               "for_model")
+        if not isinstance(step, Step):
+            raise TypeError(f"not a Step: {step!r}")
+        return self.submit(step, axes, telemetry)
+
+    def step_program(self, step: Step) -> tuple:
+        """``(ops, Program)`` of ``step``, derived once and cached."""
+        with self._lock:
+            hit = self._programs.get(step)
+        if hit is None:
+            hit = TEL.step_program(self.model, step, self.deployment)
+            with self._lock:
+                hit = self._programs.setdefault(step, hit)
+        return hit
+
+    def _derive(self, batch: list, tags: dict) -> None:
+        """Replace each step of ``batch`` by its program."""
+        steps = {r.program for r in batch if isinstance(r.program, Step)}
+        if not steps:
+            return
+        with self._lock:
+            misses = sum(s not in self._programs for s in steps)
+        with jax.profiler.TraceAnnotation("repro.service.derive", **tags,
+                                          misses=misses):
+            for r in batch:
+                if isinstance(r.program, Step):
+                    r.program = self.step_program(r.program)[1]
 
     def map(self, requests: Iterable[tuple]) -> list:
         """Submit a whole request iterable, then gather results in order.
@@ -213,6 +267,15 @@ class DVFSService:
                         break
                     batch.append(nxt)
             tags = {"batch": n_batch, "jobs": len(batch)}
+            try:
+                self._derive(batch, tags)
+            except Exception as e:  # a step with no program: fail the batch
+                for r in batch:
+                    r.future.set_exception(e)
+                if stop:
+                    self._done.put(_SHUTDOWN)
+                    return
+                continue
             # double buffering: at most `depth` dispatched batches alive —
             # this acquire is the ONLY backpressure, and it waits on the
             # collector (host-side), never on the device directly
@@ -281,8 +344,9 @@ class DVFSService:
     def stats(self) -> Dict[str, float]:
         """Sustained throughput + job-latency percentiles over every job
         resolved so far (latency = ``submit`` -> report built and future
-        resolved)."""
+        resolved), and the programs derived from model steps so far."""
         with self._lock:
+            derived = len(self._programs)
             lat = np.asarray(self._lat, np.float64)
             sizes = list(self._batch_sizes)
             wall = (self._t_last - self._t_first) \
@@ -298,6 +362,7 @@ class DVFSService:
             "p50_latency_s": float(np.percentile(lat, 50)) if n else 0.0,
             "p99_latency_s": float(np.percentile(lat, 99)) if n else 0.0,
             "max_latency_s": float(lat.max()) if n else 0.0,
+            "programs_derived": derived,
         }
 
     def reset_stats(self) -> None:

@@ -8,8 +8,9 @@ The decode loop's per-token greedy sampling lives INSIDE the jitted step
 model math instead of paying an extra un-jitted dispatch between calls),
 and with ``--dvfs`` the loop's per-step telemetry streams through the
 long-lived :class:`repro.dvfs_runtime.service.DVFSService` — periodic
-async report requests overlap decode compute instead of a single fresh
-one-shot report after the loop.
+async report requests for the loop's own decode step (its batch, its
+context) overlap decode compute instead of a single fresh one-shot report
+after the loop.
 """
 from __future__ import annotations
 
@@ -49,10 +50,9 @@ def serve(cfg, *, batch: int, prompt_len: int, gen: int, seed: int = 0,
 
     svc = futs = window = None
     if dvfs:
-        from repro.configs.base import ShapeConfig
         from repro.dvfs_runtime.service import DVFSService
-        shape = ShapeConfig("serve", prompt_len + gen, batch, "decode")
-        svc = DVFSService.for_model(cfg, shape, coalesce_s=0.001)
+        from repro.dvfs_runtime.telemetry import Step
+        svc = DVFSService.for_model(cfg, coalesce_s=0.001)
         futs, window = [], []
 
     cache = init_cache(cfg, batch, prompt_len + gen, fill=prompt_len)
@@ -70,10 +70,12 @@ def serve(cfg, *, batch: int, prompt_len: int, gen: int, seed: int = 0,
             window.append((step, t_now - t_prev))
             t_prev = t_now
             if (step + 1) % dvfs_stride == 0 or step == gen - 1:
-                # async: the service coalesces + dispatches off-thread,
+                # async: the service derives the window's last step (its
+                # batch, its context) and dispatches off-thread,
                 # overlapping the remaining decode steps
-                futs.append(svc.submit(svc.default_program,
-                                       telemetry=window))
+                futs.append(svc.submit_step(
+                    Step("decode", batch, prompt_len + step + 1),
+                    telemetry=window))
                 window = []
     jax.block_until_ready(out[-1])
     t_decode = (time.perf_counter() - t0) / gen

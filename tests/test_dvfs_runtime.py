@@ -2,9 +2,12 @@
 import numpy as np
 import pytest
 
-from repro.configs import TRAIN_4K, DECODE_32K, get_config
+from repro.configs import DECODE_32K, PREFILL_32K, TRAIN_4K, get_config
+from repro.core.simulate import SimConfig
 from repro.dvfs_runtime.manager import DVFSManager
-from repro.dvfs_runtime.telemetry import arch_program, step_ops
+from repro.dvfs_runtime.service import DVFSService
+from repro.dvfs_runtime.telemetry import (Deployment, Step, arch_program,
+                                          step_ops)
 
 
 @pytest.mark.parametrize("arch", ["llama3-405b", "rwkv6-3b", "qwen2-moe-a2.7b"])
@@ -113,3 +116,92 @@ def test_manager_grid_report():
     one = mgr.report()
     assert one["ed2p_norm"] == pytest.approx(
         reps[(1.0, "ed2p")]["ed2p_norm"])
+
+
+def _dsv3_units():
+    gpu = dict(chips=1, redundant=32, weight_bytes=1, dispatch_bytes=1,
+               combine_bytes=2, cache_bytes=2, peak_flops=989.5e12,
+               fp8_flops=1979e12, hbm_bw=3.35e12, coll_bw=50e9)
+    return {"prefill": Deployment(ep=32, dp=32, **gpu),
+            "decode": Deployment(ep=144, dp=144, **gpu)}
+
+
+def test_manager_for_model_takes_the_unit_of_its_step():
+    """A deployment per step kind: the manager's program is its shape's
+    unit's, and the default deployment keeps today's program."""
+    cfg = get_config("deepseek-v3")
+    units = _dsv3_units()
+    for shape in (PREFILL_32K, DECODE_32K):
+        mgr = DVFSManager.for_model(cfg, shape, units, n_cu=8)
+        want = arch_program(cfg, shape, units[shape.kind])
+        np.testing.assert_array_equal(np.asarray(mgr.program.sens_rate),
+                                      np.asarray(want.sens_rate))
+    glm = get_config("glm4-9b")
+    np.testing.assert_array_equal(
+        np.asarray(DVFSManager.for_model(glm, TRAIN_4K).program.i0_rate),
+        np.asarray(arch_program(glm, TRAIN_4K).i0_rate))
+    # an EP144 decode step spends most of its time in the all-to-all and
+    # the latent cache: most of its blocks are bound by them
+    ep = np.asarray(arch_program(cfg, DECODE_32K, units["decode"]).mem_frac)
+    assert np.mean(ep == 1.0) > 0.9
+
+
+def test_expert_parallel_deployment_checks_its_split():
+    with pytest.raises(ValueError, match="chips must be 1"):
+        Deployment(ep=32)
+    with pytest.raises(ValueError, match="do not split"):
+        Deployment(chips=1, ep=30).experts_held(256)
+    with pytest.raises(ValueError, match="per-device 'train'"):
+        step_ops(get_config("deepseek-v3"), TRAIN_4K, _dsv3_units()["decode"])
+    with pytest.raises(ValueError, match="MLA attention only"):
+        step_ops(get_config("glm4-9b"), DECODE_32K, _dsv3_units()["decode"])
+
+
+def test_service_step_entry_derives_each_step_once():
+    """``submit_step``: the dispatcher derives a step's program once, and
+    the job's traces equal a ``submit`` of that program."""
+    cfg = get_config("glm4-9b")
+    sim = SimConfig(n_cu=4, n_wf=8, n_epochs=16)
+    s1, s2 = Step("decode", 4, 1024), Step("decode", 4, 2048, load=2.0)
+    with DVFSService.for_model(cfg, sim=sim, max_batch=2,
+                               coalesce_s=0.001) as svc:
+        futs = [svc.submit_step(s, {"epoch_us": 1.0}) for s in (s1, s2, s1)]
+        res = [f.result(timeout=600) for f in futs]
+        assert svc.stats()["programs_derived"] == 2
+        ops, prog = svc.step_program(s1)
+        direct = svc.submit(prog, {"epoch_us": 1.0}).result(timeout=600)
+        assert svc.stats()["programs_derived"] == 2
+    for m, tr in direct["traces"].items():
+        for r in (res[0], res[2]):
+            np.testing.assert_array_equal(r["traces"][m]["work"], tr["work"])
+    np.testing.assert_array_equal(np.asarray(prog.i0_rate),
+                                  np.asarray(arch_program(cfg, s1.shape).i0_rate))
+    assert [o.name for o in ops] == [o.name for o in step_ops(cfg, s1.shape)]
+
+
+def test_service_step_entry_refusals():
+    sim = SimConfig(n_cu=4, n_wf=8, n_epochs=16)
+    with DVFSService(sim, max_batch=2) as svc:
+        with pytest.raises(RuntimeError, match="for_model"):
+            svc.submit_step(Step("decode", 4, 1024))
+    with DVFSService.for_model(get_config("deepseek-v3"), _dsv3_units(),
+                               sim=sim, max_batch=2) as svc:
+        with pytest.raises(TypeError, match="not a Step"):
+            svc.submit_step(("decode", 4, 1024))
+        # a step kind the deployment has no unit for fails its own future
+        bad = svc.submit_step(Step("train", 4, 1024))
+        with pytest.raises(KeyError):
+            bad.result(timeout=600)
+        ok = svc.submit_step(Step("decode", 128, 4096))
+        assert ok.result(timeout=600)["batch_size"] == 1
+
+
+def test_serve_loop_reports_its_own_decode_steps():
+    from repro.configs import get_smoke_config
+    from repro.launch.serve import serve
+    rep = serve(get_smoke_config("glm4-9b"), batch=2, prompt_len=8, gen=4,
+                dvfs=True, dvfs_stride=2)
+    assert rep["dvfs_requests"] == 2
+    # contexts 10 and 12: two distinct steps, each derived once
+    assert rep["dvfs_stream"]["programs_derived"] == 2
+    assert rep["dvfs"]["step_time"]["n_steps"] == 2

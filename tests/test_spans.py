@@ -169,3 +169,33 @@ def test_grid_executable_names_engine_phases(use_pallas, scopes):
         carry0, *ops[:3], seed, ids).compile().as_text()
     found = set(re.findall(r'op_name="[^"]*?(engine\.\w+)', text))
     assert found == set(scopes)
+
+
+def test_service_derive_span_counts_misses(tmp_path):
+    """``submit_step``: the dispatcher writes ``repro.service.derive``
+    ahead of the batch's admit, tagged with the batch, its jobs and the
+    programs it derived; a step seen before derives nothing."""
+    from repro.configs import get_config
+    from repro.dvfs_runtime.telemetry import Step
+    steps = [Step("decode", 4, 1024), Step("decode", 4, 2048)]
+    svc = DVFSService.for_model(get_config("glm4-9b"), sim=SIM_CFG,
+                                max_batch=2, coalesce_s=0.05)
+    svc.submit(get_workload("comd"), {}).result(timeout=600)  # compile
+
+    def stream():
+        futs = [svc.submit_step(s, {}) for s in steps]
+        for f in futs:
+            f.result(timeout=60)
+        svc.submit_step(steps[0], {}).result(timeout=60)
+        svc.close()
+
+    lines = _traced(tmp_path, stream)
+    disp = _thread_of(lines, "repro.service.derive")
+    assert disp == _thread_of(lines, "repro.service.admit")
+    derive = _named(lines[disp], "repro.service.derive")
+    admit = _named(lines[disp], "repro.service.admit")
+    assert [(st["jobs"], st["misses"]) for _, _, st in derive] == \
+        [(2, 2), (1, 0)]
+    for (_, e, st), (s, _, st2) in zip(derive, admit):
+        assert st["batch"] == st2["batch"] and e <= s
+    assert svc.stats()["programs_derived"] == 2
